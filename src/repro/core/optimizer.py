@@ -20,6 +20,7 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass, field
 
+from .. import spans
 from .cost import CostParams, plan_cost_report, select_physical_joins
 from .dp import dp_place, lift_semantic_filters, rebuild_plan
 from .plan import Catalog, Node, SemanticFilter
@@ -51,54 +52,55 @@ def optimize(
 ) -> OptimizedPlan:
     if strategy not in STRATEGIES:
         raise ValueError(f"strategy must be one of {STRATEGIES}")
-    params = params or CostParams()
-    plan = root.clone()
-    overhead: dict[str, float] = {}
+    with spans.span("planner.optimize"):
+        params = params or CostParams()
+        plan = root.clone()
+        overhead: dict[str, float] = {}
 
-    t0 = time.perf_counter()
-    plan = push_down_filters(plan, catalog)
-    overhead["pushdown"] = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    plan = simplify(plan, catalog)
-    # SJ decomposition exposes new pushdown opportunities: relational σ
-    # sinks between × and the decomposed SF (§3.2).
-    plan = push_down_filters(plan, catalog)
-    overhead["simplify"] = time.perf_counter() - t0
-
-    n_sf = sum(1 for n in plan.walk() if isinstance(n, SemanticFilter))
-
-    est_cost = None
-    dp_states = None
-    if strategy == "pullup":
         t0 = time.perf_counter()
-        plan = pull_up_semantic_filters(plan, catalog)
-        overhead["placement"] = time.perf_counter() - t0
-    elif strategy == "cost":
+        plan = push_down_filters(plan, catalog)
+        overhead["pushdown"] = time.perf_counter() - t0
+
         t0 = time.perf_counter()
-        skeleton, lifted = lift_semantic_filters(plan)
-        result = dp_place(skeleton, lifted, catalog, params)
-        plan = rebuild_plan(skeleton, lifted, result.placement, catalog)
-        overhead["placement"] = time.perf_counter() - t0
-        est_cost = result.cost
-        dp_states = result.n_states
-    else:
-        overhead["placement"] = 0.0
+        plan = simplify(plan, catalog)
+        # SJ decomposition exposes new pushdown opportunities: relational σ
+        # sinks between × and the decomposed SF (§3.2).
+        plan = push_down_filters(plan, catalog)
+        overhead["simplify"] = time.perf_counter() - t0
 
-    # physical join selection runs last: semantic placement has settled
-    # the plan shape, so build-side grouping guarantees are final
-    t0 = time.perf_counter()
-    select_physical_joins(plan, catalog, params)
-    overhead["physical_join"] = time.perf_counter() - t0
+        n_sf = sum(1 for n in plan.walk() if isinstance(n, SemanticFilter))
 
-    return OptimizedPlan(
-        plan=plan,
-        strategy=strategy,
-        n_semantic_filters=n_sf,
-        est_cost=est_cost,
-        dp_states=dp_states,
-        overhead=overhead,
-    )
+        est_cost = None
+        dp_states = None
+        if strategy == "pullup":
+            t0 = time.perf_counter()
+            plan = pull_up_semantic_filters(plan, catalog)
+            overhead["placement"] = time.perf_counter() - t0
+        elif strategy == "cost":
+            t0 = time.perf_counter()
+            skeleton, lifted = lift_semantic_filters(plan)
+            result = dp_place(skeleton, lifted, catalog, params)
+            plan = rebuild_plan(skeleton, lifted, result.placement, catalog)
+            overhead["placement"] = time.perf_counter() - t0
+            est_cost = result.cost
+            dp_states = result.n_states
+        else:
+            overhead["placement"] = 0.0
+
+        # physical join selection runs last: semantic placement has settled
+        # the plan shape, so build-side grouping guarantees are final
+        t0 = time.perf_counter()
+        select_physical_joins(plan, catalog, params)
+        overhead["physical_join"] = time.perf_counter() - t0
+
+        return OptimizedPlan(
+            plan=plan,
+            strategy=strategy,
+            n_semantic_filters=n_sf,
+            est_cost=est_cost,
+            dp_states=dp_states,
+            overhead=overhead,
+        )
 
 
 def report(plan: Node, catalog: Catalog,

@@ -56,6 +56,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from .. import spans
 from ..core.plan import (
     Aggregate,
     BoolOp,
@@ -107,11 +108,12 @@ class ExecStats:
     """Per-query execution counters mirroring the cost model's terms:
     ``llm_calls`` (distinct backend invocations = C_LLM), ``rel_rows``
     (rows through relational operators = C_rel), ``probe_rows`` (cache
-    lookups triggered by pulled-up filters), plus wall-clock splits,
-    per-operator breakdowns and ``pipeline_syncs`` — the device→host
-    fetches ``kernels.sync.HOST_SYNCS`` recorded while the plan ran
-    (every remaining fetch in the device-resident pipeline is ticked,
-    so the benchmarks can gate on the count)."""
+    lookups triggered by pulled-up filters), plus ``sem_wall_s``, the
+    wall clock of the semantic operators, and ``pipeline_syncs`` — the
+    device→host fetches ``kernels.sync.HOST_SYNCS`` recorded while the
+    plan ran (every remaining fetch in the device-resident pipeline is
+    ticked, so the benchmarks can gate on the count). Per-operator
+    times are spans (``repro.spans``), not counters."""
 
     llm_calls: int = 0
     cache_hits: int = 0
@@ -119,10 +121,7 @@ class ExecStats:
     null_skipped: int = 0
     rel_rows: int = 0
     sem_rows: int = 0
-    wall_s: float = 0.0
-    rel_wall_s: float = 0.0
     sem_wall_s: float = 0.0
-    per_op: dict = field(default_factory=dict)
     prompt_chars: int = 0
     prompts_rendered: int = 0  # host renders (distinct keys, vectorized)
     pipeline_syncs: int = 0  # data-path device→host fetches in execute()
@@ -132,11 +131,6 @@ class ExecStats:
     # ("hash" | "stream" | "sort_merge" | "partitioned" | "host" |
     # "reference")
     join_physical: dict = field(default_factory=dict)
-
-    def bump(self, op: str, key: str, v: float) -> None:
-        """Accumulate ``v`` under ``per_op[op][key]``."""
-        d = self.per_op.setdefault(op, {})
-        d[key] = d.get(key, 0) + v
 
 
 class ExecutionError(RuntimeError):
@@ -208,48 +202,42 @@ class Executor:
         """Run ``plan`` to a materialised ``Table`` plus its
         ``ExecStats`` (resetting the per-query cache scope first unless
         constructed with ``fresh_cache_per_query=False``)."""
-        if self.fresh_cache_per_query:
-            self.runner.reset_query_scope()
-        stats = ExecStats()
-        t0 = time.perf_counter()
-        syncs0 = HOST_SYNCS.syncs
-        serving0 = HOST_SYNCS.site_total(SERVING_SITES)
-        coll0 = HOST_SYNCS.collectives
-        table = self._run(plan, stats)
-        stats.wall_s = time.perf_counter() - t0
-        stats.collective_ops = HOST_SYNCS.collectives - coll0
-        # serving-tier fetches scale with decode length, not with the
-        # data path — split them out so pipeline_syncs budgets compare
-        # across serving disciplines (drained vs continuous)
-        stats.serving_syncs = HOST_SYNCS.site_total(SERVING_SITES) - serving0
-        stats.pipeline_syncs = (HOST_SYNCS.syncs - syncs0
-                                - stats.serving_syncs)
+        with spans.span(spans.QUERY):
+            if self.fresh_cache_per_query:
+                self.runner.reset_query_scope()
+            stats = ExecStats()
+            syncs0 = HOST_SYNCS.syncs
+            serving0 = HOST_SYNCS.site_total(SERVING_SITES)
+            coll0 = HOST_SYNCS.collectives
+            table = self._run(plan, stats)
+            stats.collective_ops = HOST_SYNCS.collectives - coll0
+            # serving-tier fetches scale with decode length, not with the
+            # data path — split them out so pipeline_syncs budgets compare
+            # across serving disciplines (drained vs continuous)
+            stats.serving_syncs = (HOST_SYNCS.site_total(SERVING_SITES)
+                                   - serving0)
+            stats.pipeline_syncs = (HOST_SYNCS.syncs - syncs0
+                                    - stats.serving_syncs)
         return table, stats
 
     # ------------------------------------------------------------ dispatch
     def _run(self, node: Node, stats: ExecStats) -> Table:
-        t0 = time.perf_counter()
-        name = type(node).__name__
         if isinstance(node, Scan):
-            out = self.db.tables[node.table]
-            stats.rel_rows += out.num_valid
-            stats.bump(name, "rows", out.num_valid)
-            stats.rel_wall_s += time.perf_counter() - t0
+            with spans.span("executor.Scan"):
+                out = self.db.tables[node.table]
+                stats.rel_rows += out.num_valid
             return out
+        children = [self._run(c, stats) for c in node.children]
         if isinstance(node, (SemanticFilter, SemanticProject, SemanticJoin)):
-            children = [self._run(c, stats) for c in node.children]
             t0 = time.perf_counter()
-            out = self._run_semantic(node, children, stats)
+            with spans.span("semantic", type(node).__name__):
+                out = self._run_semantic(node, children, stats)
             stats.sem_wall_s += time.perf_counter() - t0
             return out
-
-        children = [self._run(c, stats) for c in node.children]
-        t0 = time.perf_counter()
-        out = self._run_relational(node, children, stats)
-        in_rows = sum(c.num_valid for c in children)
-        stats.rel_rows += in_rows + out.num_valid
-        stats.bump(name, "rows", in_rows + out.num_valid)
-        stats.rel_wall_s += time.perf_counter() - t0
+        with spans.span("executor", type(node).__name__):
+            out = self._run_relational(node, children, stats)
+            stats.rel_rows += (sum(c.num_valid for c in children)
+                               + out.num_valid)
         return out
 
     # ------------------------------------------------------------ relational
@@ -817,17 +805,17 @@ class Executor:
     def _evaluate_vectorized(self, node: Node, child: Table,
                              stats: ExecStats, out_dtype: str
                              ) -> tuple[Table, SemanticResult, np.ndarray]:
-        tc = child.compact(self.kernel_impl)
-        n = tc.capacity
-        rts, id_cols = self._ref_id_columns(tc, node.ref_tables)
-        stats.sem_rows += n
-        stats.probe_rows += n
-
-        if n == 0:
-            res = SemanticResult(values=[], distinct_calls=0, cache_hits=0,
-                                 null_rows=0, prompts_rendered=0)
-            inverse = np.zeros(0, dtype=np.int64)
-        else:
+        with spans.span("semantic.dedup"):
+            tc = child.compact(self.kernel_impl)
+            n = tc.capacity
+            rts, id_cols = self._ref_id_columns(tc, node.ref_tables)
+            stats.sem_rows += n
+            stats.probe_rows += n
+            if n == 0:
+                res = SemanticResult(values=[], distinct_calls=0,
+                                     cache_hits=0, null_rows=0,
+                                     prompts_rendered=0)
+                return tc, res, np.zeros(0, dtype=np.int64)
             # placeholder-free φ references no tables: every row shares one
             # constant key, so a single representative covers the batch
             keys = (np.stack(id_cols, axis=1) if id_cols
@@ -835,7 +823,6 @@ class Executor:
             keys = np.ascontiguousarray(keys, dtype=np.int32)
             _, reps, inverse, rep_hashes = dedup_representatives(
                 keys, return_hashes=True, impl=self.kernel_impl)
-            rep_ctxs = [self._context_at(rts, id_cols, int(r)) for r in reps]
             counts = np.bincount(inverse, minlength=len(reps))
             # key-probe fast path: the kernel's row hash + exact key row
             # let the FunctionCache recognise representatives seen by an
@@ -847,10 +834,11 @@ class Executor:
             key_fps = (hash_rows_np(keys[reps], basis=FP_BASIS)
                        if (self.runner.cache.verdicts.enabled
                            and out_dtype == "bool") else None)
-            res = self.runner.evaluate_unique(
-                node.phi, rep_ctxs, counts=counts, out_dtype=out_dtype,
-                key_ids=key_ids, key_hashes=rep_hashes, key_fps=key_fps)
-
+        with spans.span("semantic.render"):
+            rep_ctxs = [self._context_at(rts, id_cols, int(r)) for r in reps]
+        res = self.runner.evaluate_unique(
+            node.phi, rep_ctxs, counts=counts, out_dtype=out_dtype,
+            key_ids=key_ids, key_hashes=rep_hashes, key_fps=key_fps)
         return tc, res, inverse
 
     def _run_semantic(self, node: Node, ch: list[Table],
@@ -865,25 +853,28 @@ class Executor:
         if isinstance(node, SemanticFilter):
             tc, res, inverse = self._evaluate_semantic(
                 node, ch[0], stats, out_dtype="bool")
-            stats.bump(f"SF{node.sf_id}", "calls", res.distinct_calls)
-            rep_mask = np.asarray([bool(v) for v in res.values], dtype=bool)
-            mask = rep_mask[inverse] if len(inverse) else np.zeros(0, bool)
-            return tc.with_mask(jnp.asarray(mask))
+            with spans.span("semantic.bind"):
+                rep_mask = np.asarray([bool(v) for v in res.values],
+                                      dtype=bool)
+                mask = (rep_mask[inverse] if len(inverse)
+                        else np.zeros(0, bool))
+                return tc.with_mask(jnp.asarray(mask))
 
         if isinstance(node, SemanticProject):
             tc, res, inverse = self._evaluate_semantic(
                 node, ch[0], stats, out_dtype=node.out_dtype)
-            stats.bump("SP", "calls", res.distinct_calls)
-            rep_vals = np.asarray(
-                [float(v) if v is not None else np.nan for v in res.values],
-                dtype=np.float32,
-            )
-            vals = rep_vals[inverse] if len(inverse) else \
-                np.zeros(0, np.float32)
-            cols = dict(tc.columns)
-            cols[node.out_col] = jnp.asarray(vals)
-            return Table(columns=cols, valid=tc.valid,
-                         _num_valid=tc._num_valid)
+            with spans.span("semantic.bind"):
+                rep_vals = np.asarray(
+                    [float(v) if v is not None else np.nan
+                     for v in res.values],
+                    dtype=np.float32,
+                )
+                vals = rep_vals[inverse] if len(inverse) else \
+                    np.zeros(0, np.float32)
+                cols = dict(tc.columns)
+                cols[node.out_col] = jnp.asarray(vals)
+                return Table(columns=cols, valid=tc.valid,
+                             _num_valid=tc._num_valid)
 
         raise ExecutionError(f"unsupported semantic node {type(node)}")
 

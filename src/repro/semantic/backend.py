@@ -23,6 +23,8 @@ import time
 from dataclasses import dataclass
 from typing import Callable, Optional, Sequence
 
+from .. import spans
+
 
 class Backend:
     """Interface: evaluate a batch of rendered prompts.
@@ -138,25 +140,28 @@ class ModelBackend(Backend):
     def submit_batch(self, prompts, contexts, weights=None):
         """Enqueue one chunk on the continuous scheduler; returns an
         opaque handle for ``collect``. Does not block on the device."""
-        prompts = list(prompts)
-        self.calls += len(prompts)
-        ticket = self.engine.submit(prompts, weights=weights)
-        return ticket, list(contexts)
+        with spans.span("backend.submit"):
+            prompts = list(prompts)
+            self.calls += len(prompts)
+            ticket = self.engine.submit(prompts, weights=weights)
+            return ticket, list(contexts)
 
     def collect(self, handles):
         """Drain every submitted ticket and parse answers, in order."""
         out = []
-        for ticket, ctxs in handles:
-            self.engine.drain(ticket)
-            raw = self.engine.answers(ticket)
-            out.extend(self._parse(r, ctx) for r, ctx in zip(raw, ctxs))
+        with spans.span("backend.collect"):
+            for ticket, ctxs in handles:
+                self.engine.drain(ticket)
+                raw = self.engine.answers(ticket)
+                out.extend(self._parse(r, ctx) for r, ctx in zip(raw, ctxs))
         return out
 
     # ------------------------------------------------------ sync path
     def evaluate_batch(self, prompts, contexts):
-        self.calls += len(prompts)
-        raw = self.answer_fn(list(prompts))
-        return [self._parse(r, ctx) for r, ctx in zip(raw, contexts)]
+        with spans.span("backend.evaluate"):
+            self.calls += len(prompts)
+            raw = self.answer_fn(list(prompts))
+            return [self._parse(r, ctx) for r, ctx in zip(raw, contexts)]
 
     def _parse(self, r, ctx):
         dtype = ctx.get("__dtype__", self.out_dtype)

@@ -38,6 +38,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from .. import spans
 from .backend import Backend
 from .cache import (
     KEY_MISS,
@@ -205,35 +206,38 @@ class SemanticRunner:
         use_table = (vt.enabled and out_dtype == "bool"
                      and key_hashes is not None and key_fps is not None
                      and len(contexts) > 0)
-        table_v = vt.probe(phi, key_hashes, key_fps) if use_table else None
-        if key_ids is not None:
-            known = self.cache.probe_keys([(phi, k) for k in key_ids])
-        else:
-            known = None
+        with spans.span("semantic.probe"):
+            table_v = (vt.probe(phi, key_hashes, key_fps) if use_table
+                       else None)
+            if key_ids is not None:
+                known = self.cache.probe_keys([(phi, k) for k in key_ids])
+            else:
+                known = None
         prompts: list[object] = []
         resolved: dict[int, bool] = {}
         table_null: set[int] = set()
         rendered = 0
         new_bindings: list[tuple[object, Optional[str]]] = []
-        for i, ctx in enumerate(contexts):
-            if table_v is not None and table_v[i] != VERDICT_MISS:
-                if table_v[i] == VERDICT_NULL:
-                    table_null.add(i)
-                    prompts.append(None)
-                else:
-                    resolved[i] = bool(table_v[i] == VERDICT_TRUE)
-                    prompts.append(_TABLE_HIT)
-                continue
-            if known is not None and known[i] is not KEY_MISS:
-                prompts.append(known[i])
-                continue
-            p = render_prompt(phi, ctx)
-            rendered += 1
-            prompts.append(p)
-            if key_ids is not None:
-                new_bindings.append(((phi, key_ids[i]), p))
-        if new_bindings:
-            self.cache.bind_keys(new_bindings)
+        with spans.span("semantic.render"):
+            for i, ctx in enumerate(contexts):
+                if table_v is not None and table_v[i] != VERDICT_MISS:
+                    if table_v[i] == VERDICT_NULL:
+                        table_null.add(i)
+                        prompts.append(None)
+                    else:
+                        resolved[i] = bool(table_v[i] == VERDICT_TRUE)
+                        prompts.append(_TABLE_HIT)
+                    continue
+                if known is not None and known[i] is not KEY_MISS:
+                    prompts.append(known[i])
+                    continue
+                p = render_prompt(phi, ctx)
+                rendered += 1
+                prompts.append(p)
+                if key_ids is not None:
+                    new_bindings.append(((phi, key_ids[i]), p))
+            if new_bindings:
+                self.cache.bind_keys(new_bindings)
         if counts is None:
             counts = [1] * len(prompts)
         live_idx = [i for i, p in enumerate(prompts)
@@ -259,29 +263,32 @@ class SemanticRunner:
 
             def build_ctx(chunk_keys):
                 batch_ctx = []
-                for k in chunk_keys:
-                    c = dict(key_to_ctx[k])
-                    c["__phi__"] = phi
-                    c["__dtype__"] = out_dtype
-                    batch_ctx.append(c)
+                with spans.span("semantic.render"):
+                    for k in chunk_keys:
+                        c = dict(key_to_ctx[k])
+                        c["__phi__"] = phi
+                        c["__dtype__"] = out_dtype
+                        batch_ctx.append(c)
                 return batch_ctx
 
             mk = list(missing_keys)
             return self._dispatch(mk, build_ctx,
                                   weights=[row_weight[k] for k in mk])
 
-        live_results = self.cache.lookup_batch(
-            [prompts[i] for i in live_idx], compute,
-            counts=[counts[i] for i in live_idx],
-        )
-        values: list[object] = [None] * len(prompts)
-        for i, r in zip(live_idx, live_results):
-            values[i] = r
-        for i, v in resolved.items():
-            values[i] = v
-        if use_table:
-            self._bind_verdicts(vt, phi, key_hashes, key_fps, prompts,
-                                values, resolved.keys() | table_null)
+        with spans.span("semantic.probe"):
+            live_results = self.cache.lookup_batch(
+                [prompts[i] for i in live_idx], compute,
+                counts=[counts[i] for i in live_idx],
+            )
+        with spans.span("semantic.bind"):
+            values: list[object] = [None] * len(prompts)
+            for i, r in zip(live_idx, live_results):
+                values[i] = r
+            for i, v in resolved.items():
+                values[i] = v
+            if use_table:
+                self._bind_verdicts(vt, phi, key_hashes, key_fps, prompts,
+                                    values, resolved.keys() | table_null)
         return SemanticResult(
             values=values,
             distinct_calls=self.cache.stats.misses - misses_before,

@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Optional, Sequence
 
 import jax
@@ -47,6 +46,8 @@ class ServingStats:
     prompts: int = 0
     batches: int = 0  # prefill launches (any width)
     prefill_tokens: int = 0  # real prompt tokens only, never padding
+    # token positions prefill launches computed, padding included
+    prefill_token_slots: int = 0
     decode_steps: int = 0  # decode rounds (one device step each)
     wall_s: float = 0.0
     # --- slot occupancy ---
@@ -84,6 +85,7 @@ class ServingStats:
             "prompts": self.prompts,
             "batches": self.batches,
             "prefill_tokens": self.prefill_tokens,
+            "prefill_token_slots": self.prefill_token_slots,
             "decode_steps": self.decode_steps,
             "decode_tokens": self.decode_tokens,
             "wall_s": self.wall_s,
@@ -170,13 +172,18 @@ class ServingEngine:
             rem = rem.at[slots].set(max_new, mode="drop")
             return cache, cur, pos, live, rem
 
+        def _decode_round(params, cache, cur, pos, live, rem):
+            # a named function, so the program is ``jit__decode_round``
+            # on a profiler trace
+            return decode_round(cfg, policy, (yes, no), params, cache, cur,
+                                pos, live, rem)
+
         self._prefill = jax.jit(_prefill)
         self._decode = jax.jit(_decode, donate_argnums=(1,))
         self._prefill_insert = jax.jit(_prefill_insert,
                                        donate_argnums=(1, 2, 3, 4, 5))
-        self._decode_round = jax.jit(
-            partial(decode_round, cfg, policy, (yes, no)),
-            donate_argnums=(1, 2, 3, 4, 5))
+        self._decode_round = jax.jit(_decode_round,
+                                     donate_argnums=(1, 2, 3, 4, 5))
         self.scheduler = SlotScheduler(self)
 
     @property
@@ -272,6 +279,7 @@ class ServingEngine:
         # shape cannot avoid; count only real prompt tokens and report
         # the waste through the occupancy counters
         self.stats.prefill_tokens += int(lens[:len(chunk)].sum())
+        self.stats.prefill_token_slots += toks.size
         self.stats.prefill_rows += self.batch_size
         self.stats.live_prefill_rows += len(chunk)
         logits, cache = self._prefill(self.params, jnp.asarray(toks))

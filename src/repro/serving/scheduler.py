@@ -51,6 +51,7 @@ from typing import Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from .. import spans
 from ..kernels.sync import HOST_SYNCS
 from ..models import init_cache
 
@@ -141,16 +142,17 @@ class SlotScheduler:
         eng = self.engine
         now = time.perf_counter()
         rids = []
-        for i, p in enumerate(prompts):
-            toks, n = eng.encode_row(p)
-            wt = float(weights[i]) if weights is not None else 1.0
-            req = Request(rid=self._next_rid, prompt=p, tokens=toks,
-                          length=n, weight=max(wt, 1e-9),
-                          seq=self._next_rid, t_submit=now)
-            self._next_rid += 1
-            self._reqs[req.rid] = req
-            heapq.heappush(self._queue, (req.vkey, req))
-            rids.append(req.rid)
+        with spans.span("serving.encode"):
+            for i, p in enumerate(prompts):
+                toks, n = eng.encode_row(p)
+                wt = float(weights[i]) if weights is not None else 1.0
+                req = Request(rid=self._next_rid, prompt=p, tokens=toks,
+                              length=n, weight=max(wt, 1e-9),
+                              seq=self._next_rid, t_submit=now)
+                self._next_rid += 1
+                self._reqs[req.rid] = req
+                heapq.heappush(self._queue, (req.vkey, req))
+                rids.append(req.rid)
         eng.stats.prompts += len(rids)
         eng.stats.queued_peak = max(eng.stats.queued_peak,
                                     len(self._queue))
@@ -166,34 +168,37 @@ class SlotScheduler:
         eng = self.engine
         free = self.free_slots()
         while self._queue and free:
-            k = min(len(free), len(self._queue))
-            width = next(w for w in self.buckets if w <= k)
-            batch = [heapq.heappop(self._queue)[1] for _ in range(width)]
-            # packed admission batch: token rows plus (slot, length) in
-            # the last two columns — ONE upload per admission
-            adm = np.zeros((width, eng.max_seq + 2), dtype=np.int32)
-            now = time.perf_counter()
-            real_tokens = 0
-            for j, req in enumerate(batch):
-                adm[j, :eng.max_seq] = req.tokens
-                slot = free.pop(0)
-                adm[j, -2] = slot
-                adm[j, -1] = req.length
-                real_tokens += req.length
-                self._slot_req[slot] = req
-                req.t_admit = now
-                wait = now - req.t_submit
-                eng.stats.queue_wait_s += wait
-                eng.stats.queue_wait_max_s = max(
-                    eng.stats.queue_wait_max_s, wait)
-            (self._cache, self._cur, self._pos, self._live,
-             self._rem) = eng._prefill_insert(
-                eng.params, self._cache, self._cur, self._pos,
-                self._live, self._rem, jnp.asarray(adm))
-            eng.stats.batches += 1
-            eng.stats.prefill_tokens += real_tokens
-            eng.stats.prefill_rows += width
-            eng.stats.live_prefill_rows += width
+            with spans.span("serving.admit"):
+                k = min(len(free), len(self._queue))
+                width = next(w for w in self.buckets if w <= k)
+                batch = [heapq.heappop(self._queue)[1] for _ in range(width)]
+                # packed admission batch: token rows plus (slot, length) in
+                # the last two columns — ONE upload per admission
+                adm = np.zeros((width, eng.max_seq + 2), dtype=np.int32)
+                now = time.perf_counter()
+                real_tokens = 0
+                for j, req in enumerate(batch):
+                    adm[j, :eng.max_seq] = req.tokens
+                    slot = free.pop(0)
+                    adm[j, -2] = slot
+                    adm[j, -1] = req.length
+                    real_tokens += req.length
+                    self._slot_req[slot] = req
+                    req.t_admit = now
+                    wait = now - req.t_submit
+                    eng.stats.queue_wait_s += wait
+                    eng.stats.queue_wait_max_s = max(
+                        eng.stats.queue_wait_max_s, wait)
+                (self._cache, self._cur, self._pos, self._live,
+                 self._rem) = eng._prefill_insert(
+                    eng.params, self._cache, self._cur, self._pos,
+                    self._live, self._rem, jnp.asarray(adm))
+                eng.stats.batches += 1
+                eng.stats.prefill_tokens += real_tokens
+                # every token position the launch computes, padding included
+                eng.stats.prefill_token_slots += width * eng.max_seq
+                eng.stats.prefill_rows += width
+                eng.stats.live_prefill_rows += width
 
     # ------------------------------------------------------------- round
     def _round(self) -> None:
@@ -203,25 +208,27 @@ class SlotScheduler:
         live = self.live_slots()
         if not live:
             return
-        b = eng.batch_size
-        (self._cache, self._cur, self._pos, self._live, self._rem,
-         packed) = eng._decode_round(eng.params, self._cache, self._cur,
-                                     self._pos, self._live, self._rem)
-        out = np.asarray(packed)  # THE one host sync of this round
-        HOST_SYNCS.tick(site="serving_round")
-        emit, fin = out[:b], out[b:] != 0
-        eng.stats.decode_steps += 1
-        eng.stats.slot_steps += b
-        eng.stats.live_slot_steps += len(live)
-        eng.stats.decode_tokens += len(live)
-        now = time.perf_counter()
-        for s in live:
-            req = self._slot_req[s]
-            req.out_ids.append(int(emit[s]))
-            if fin[s]:
-                req.t_done = now
-                eng.stats.ttv_s.append(now - req.t_submit)
-                self._slot_req[s] = None  # slot freed mid-decode
+        with spans.span("serving.round"):
+            b = eng.batch_size
+            (self._cache, self._cur, self._pos, self._live, self._rem,
+             packed) = eng._decode_round(eng.params, self._cache, self._cur,
+                                         self._pos, self._live, self._rem)
+            with spans.span("serving.fetch"):
+                out = np.asarray(packed)  # THE one host sync of this round
+            HOST_SYNCS.tick(site="serving_round")
+            emit, fin = out[:b], out[b:] != 0
+            eng.stats.decode_steps += 1
+            eng.stats.slot_steps += b
+            eng.stats.live_slot_steps += len(live)
+            eng.stats.decode_tokens += len(live)
+            now = time.perf_counter()
+            for s in live:
+                req = self._slot_req[s]
+                req.out_ids.append(int(emit[s]))
+                if fin[s]:
+                    req.t_done = now
+                    eng.stats.ttv_s.append(now - req.t_submit)
+                    self._slot_req[s] = None  # slot freed mid-decode
 
     # -------------------------------------------------------------- loop
     def poll(self) -> int:

@@ -79,6 +79,35 @@ class TestServingEngine:
         engine.answer(["one more prompt"])
         assert engine.stats.decode_steps > before
 
+    def test_serving_programs_keep_their_trace_names(self, engine):
+        """A profiler trace names each program by its HLO module; the
+        benchmark's kernels readers look for these two names."""
+        s = engine.scheduler
+        state = (engine.params, s._cache, s._cur, s._pos, s._live, s._rem)
+        adm = np.zeros((2, engine.max_seq + 2), dtype=np.int32)
+        for fn, args, name in (
+                (engine._prefill_insert, state + (adm,),
+                 "jit__prefill_insert"),
+                (engine._decode_round, state, "jit__decode_round")):
+            first = fn.lower(*args).as_text().split("\n", 1)[0]
+            assert first.startswith(f"module @{name} "), first
+
+    def test_prefill_token_slots_count_padding(self):
+        """Both disciplines count every prefilled position, padding
+        included, beside the real prompt tokens."""
+        eng = _make_engine()
+        eng.stats = ServingStats()
+        prompts = [f"short prompt {i}" for i in range(5)]
+        eng.answer(prompts)  # buckets 4 + 1
+        assert eng.stats.prefill_token_slots == 5 * eng.max_seq
+        eng.stats = ServingStats()
+        eng.answer_drained(prompts)  # two full-width chunks
+        assert eng.stats.prefill_token_slots == 2 * 4 * eng.max_seq
+        assert eng.stats.prefill_tokens == sum(
+            eng.encode_row(p)[1] for p in prompts)
+        assert eng.stats.snapshot()["prefill_token_slots"] == \
+            eng.stats.prefill_token_slots
+
 
 class TestSlotScheduler:
     def test_slot_freed_mid_decode_is_reused(self):
