@@ -212,7 +212,8 @@ def main(argv=None) -> int:
         print(f"{name:>11}: wall={r['wall_s']:.2f}s  "
               f"tokens/s={r['tokens_per_s']:.0f}  "
               f"prompts={sv['prompts']}  batches={sv['batches']}  "
-              f"rounds={sv['decode_steps']}  "
+              f"rounds={sv['rounds']}  "
+              f"decode_steps={sv['decode_steps']}  "
               f"occupancy={sv['occupancy']:.2f}  "
               f"prefill_occupancy={sv['prefill_occupancy']:.2f}  "
               f"ttv_p50={sv['ttv_p50_s'] * 1e3:.2f}ms  "

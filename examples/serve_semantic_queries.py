@@ -89,7 +89,8 @@ def main():
         print(f"distinct model calls={stats.llm_calls}  "
               f"cache hits={stats.cache_hits}  wall={wall:.1f}s")
         print(f"serving: {engine.stats.batches} batches, "
-              f"{engine.stats.decode_steps} decode rounds, "
+              f"{engine.stats.decode_steps} decode steps, "
+              f"{engine.stats.prefill_answers} answered at prefill, "
               f"{engine.stats.prefill_tokens} prefill tokens, "
               f"occupancy={engine.stats.occupancy:.2f}")
 

@@ -73,7 +73,8 @@ def main(argv=None):
         print(f"  {p!r} -> {a}")
     s = engine.stats
     print(f"[serve] {s.prompts} prompts, {s.batches} batches, "
-          f"{s.decode_steps} decode steps, {s.wall_s:.2f}s")
+          f"{s.decode_steps} decode steps, {s.prefill_answers} answered "
+          f"at prefill, {s.wall_s:.2f}s")
 
 
 if __name__ == "__main__":

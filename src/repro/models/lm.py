@@ -350,8 +350,11 @@ def cache_specs(cfg, batch_size, max_seq, policy: ShardingPolicy):
 
 
 def prefill(cfg: ModelConfig, policy: ShardingPolicy, params, batch,
-            max_seq: Optional[int] = None):
-    """Run the full prompt, build the decode cache, return last logits."""
+            max_seq: Optional[int] = None, last=None):
+    """Run the full prompt, build the decode cache, return last logits:
+    those of the last position, or with ``last`` ((B,) int32) those of
+    position ``last[b]`` of each row, e.g. its last real token before
+    padding."""
     h, positions, mode, prefix, enc_out, enc_pos, n_img = _prepare_inputs(
         cfg, policy, params, batch)
     B, S = h.shape[0], h.shape[1]
@@ -391,8 +394,10 @@ def prefill(cfg: ModelConfig, policy: ShardingPolicy, params, batch,
                                  params["blocks"]["xattn"]["wk"])
         cache["xv"] = jnp.einsum("bsd,ldhk->lbshk", enc_out,
                                  params["blocks"]["xattn"]["wv"])
+    h = h[:, -1:] if last is None else jnp.take_along_axis(
+        h, last[:, None, None], axis=1)
     logits = _lm_logits(cfg, params,
-                        rms_norm(h[:, -1:], params["final_ln"], cfg.norm_eps))
+                        rms_norm(h, params["final_ln"], cfg.norm_eps))
     return logits[:, 0], cache
 
 

@@ -6,16 +6,20 @@ weights and one tokenizer:
 
 * **Continuous** (the default, ``answer`` / ``submit`` / ``poll`` /
   ``drain``): a ``SlotScheduler`` admits queued prompts into freed
-  slots *mid-decode* via per-slot prefill-into-cache, decodes over
-  whatever slot mix is live, and detects completion on device — one
-  host sync per scheduling round (site ``serving_round``). ``answer``
-  is a thin submit-all/await-all wrapper over the async API.
+  slots *mid-decode* via per-slot prefill-into-cache. Prefill emits
+  each request's first token from the logits at its last prompt
+  token, so a request answered there (YES/NO, or a budget of one
+  token) never costs a decode step; the rest decode over whatever
+  slot mix is live, with completion detected on device — one host
+  sync per scheduling round (site ``serving_round``). ``answer`` is a
+  thin submit-all/await-all wrapper over the async API.
 * **Drained** (``answer_drained``): the legacy drain-per-batch
   baseline — pad each chunk to ``batch_size``, prefill, decode to
   completion with a per-step host fetch (site ``serving_decode``),
-  only then admit the next chunk. Kept as the comparison baseline for
-  ``benchmarks/bench_serving.py`` and the equivalence tests; the two
-  paths are verdict-for-verdict identical.
+  only then admit the next chunk. Its first decode step re-derives the
+  token at each prompt's last position. Kept as the comparison
+  baseline for ``benchmarks/bench_serving.py`` and the equivalence
+  tests; the two paths are verdict-for-verdict identical.
 
 Both disciplines account into ``ServingStats``, which tracks slot
 occupancy (live vs padded/idle slot-steps in prefill and decode),
@@ -48,14 +52,18 @@ class ServingStats:
     prefill_tokens: int = 0  # real prompt tokens only, never padding
     # token positions prefill launches computed, padding included
     prefill_token_slots: int = 0
-    decode_steps: int = 0  # decode rounds (one device step each)
+    decode_steps: int = 0  # decode programs launched (one step each)
+    rounds: int = 0  # continuous scheduling rounds that fetched
+    prefill_answers: int = 0  # requests finished by prefill's own token
     wall_s: float = 0.0
     # --- slot occupancy ---
     prefill_rows: int = 0  # rows prefilled, incl. dead padded slots
     live_prefill_rows: int = 0  # rows that carried a real prompt
-    slot_steps: int = 0  # batch_size × decode rounds
+    slot_steps: int = 0  # batch_size × decode steps
     live_slot_steps: int = 0  # slots decoding a live request
-    decode_tokens: int = 0  # tokens emitted for live requests
+    # tokens emitted for live requests (the continuous path's first
+    # token of each comes from prefill)
+    decode_tokens: int = 0
     # --- queue latency / time-to-verdict ---
     queue_wait_s: float = 0.0  # total submit→admit wait
     queue_wait_max_s: float = 0.0
@@ -87,6 +95,8 @@ class ServingStats:
             "prefill_tokens": self.prefill_tokens,
             "prefill_token_slots": self.prefill_token_slots,
             "decode_steps": self.decode_steps,
+            "rounds": self.rounds,
+            "prefill_answers": self.prefill_answers,
             "decode_tokens": self.decode_tokens,
             "wall_s": self.wall_s,
             "occupancy": self.occupancy,
@@ -151,7 +161,8 @@ class ServingEngine:
         def _decode(params, cache, tok, pos):
             return decode_step(cfg, policy, params, cache, tok, pos)
 
-        def _prefill_insert(params, cache, cur, pos, live, rem, adm):
+        def _prefill_insert(params, cache, cur, pos, live, rem, head,
+                            adm):
             # per-slot prefill-into-cache: prefill at the admission
             # width, then scatter every cache leaf's rows (batch axis 1)
             # into the shared decode cache at the assigned slots.
@@ -159,29 +170,40 @@ class ServingEngine:
             # the slot index and real length in the last two columns —
             # so each admission pays ONE host->device upload
             toks, slots, lens = adm[:, :-2], adm[:, -2], adm[:, -1]
-            _, new = prefill(cfg, policy, params, {"tokens": toks},
-                             max_seq=cache_len)
+            # the logits at the last real token are those the first
+            # decode step would re-derive: emit the first token here
+            logits, new = prefill(cfg, policy, params, {"tokens": toks},
+                                  max_seq=cache_len,
+                                  last=jnp.maximum(lens - 1, 0))
             cache = {k: v.at[:, slots].set(new[k], mode="drop")
                      for k, v in cache.items()}
-            width = toks.shape[0]
-            last = jnp.maximum(lens - 1, 0)
-            first = toks[jnp.arange(width), last]
+            first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            fin = (first == yes) | (first == no) | (max_new <= 1)
             cur = cur.at[slots].set(first, mode="drop")
-            pos = pos.at[slots].set(last, mode="drop")
-            live = live.at[slots].set(True, mode="drop")
-            rem = rem.at[slots].set(max_new, mode="drop")
-            return cache, cur, pos, live, rem
+            pos = pos.at[slots].set(lens, mode="drop")
+            live = live.at[slots].set(~fin, mode="drop")
+            rem = rem.at[slots].set(max_new - 1, mode="drop")
+            # per-slot (first ‖ finished) of the admissions, for the
+            # scheduler's next fetch
+            b = cur.shape[0]
+            head = head.at[slots].set(first, mode="drop")
+            head = head.at[slots + b].set(fin.astype(jnp.int32),
+                                          mode="drop")
+            return cache, cur, pos, live, rem, head
 
-        def _decode_round(params, cache, cur, pos, live, rem):
+        def _decode_round(params, cache, cur, pos, live, rem, head):
             # a named function, so the program is ``jit__decode_round``
-            # on a profiler trace
-            return decode_round(cfg, policy, (yes, no), params, cache, cur,
-                                pos, live, rem)
+            # on a profiler trace. The packed result leads with ``head``,
+            # so the round's one fetch also reports the admissions
+            # since the last fetch
+            *state, packed = decode_round(cfg, policy, (yes, no), params,
+                                          cache, cur, pos, live, rem)
+            return (*state, jnp.concatenate([head, packed]))
 
         self._prefill = jax.jit(_prefill)
         self._decode = jax.jit(_decode, donate_argnums=(1,))
         self._prefill_insert = jax.jit(_prefill_insert,
-                                       donate_argnums=(1, 2, 3, 4, 5))
+                                       donate_argnums=(1, 2, 3, 4, 5, 6))
         self._decode_round = jax.jit(_decode_round,
                                      donate_argnums=(1, 2, 3, 4, 5))
         self.scheduler = SlotScheduler(self)

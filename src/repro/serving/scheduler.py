@@ -15,20 +15,23 @@ plus a slot table over ONE shared decode cache:
   first), so a partial chunk never pays a full-batch prefill: every
   prefilled row is a real request (the drained path's dead-slot waste
   is *skipped*, not just masked). Each admission batch runs the
-  engine's ``_prefill_insert`` jit: prefill at the bucket width, then
-  scatter the new K/V rows, first token, position, liveness and
-  remaining-token budget into the shared cache at the assigned slot
-  indices — prefill-into-cache at a slot offset, jit'd alongside the
-  whole-batch prefill.
-* **round** — one decode step over whatever mix of slots is live
-  (freshly admitted prompts decode next to half-finished ones: prefill
-  and decode interleave instead of alternating in lockstep). Done
-  detection runs ON DEVICE (answer-token hit or token budget
-  exhausted) and the round fetches a single packed (emit ‖ finished)
-  vector — ONE host sync per scheduling round, ticked as site
-  ``serving_round``. A finished sequence frees its slot mid-decode;
-  the next admission recycles it while the rest of the batch keeps
-  decoding.
+  engine's ``_prefill_insert`` jit: prefill at the bucket width, emit
+  each request's first token from the logits at its last prompt token,
+  then scatter the new K/V rows, that token, the next position,
+  liveness (not finished at the first token) and remaining-token budget
+  into the shared cache at the assigned slot indices, and the (first ‖
+  finished) pair into a per-slot ``head`` vector — prefill-into-cache
+  at a slot offset, jit'd alongside the whole-batch prefill.
+* **round** — ONE packed device→host fetch, ticked as site
+  ``serving_round``. If a slot is known to be live past its first
+  token, the round first launches one decode step over whatever mix
+  of slots is live (freshly admitted prompts decode next to
+  half-finished ones: prefill and decode interleave instead of
+  alternating in lockstep), and fetches ``head`` ‖ (emit ‖ finished);
+  otherwise it fetches ``head`` alone and launches no decode step.
+  Done detection runs ON DEVICE (answer-token hit or token budget
+  exhausted). A finished sequence frees its slot mid-decode; the next
+  admission recycles it while the rest of the batch keeps decoding.
 
 Fairness: admission order is ascending ``seq / weight`` (stable by
 ``seq``). Equal weights degenerate to FIFO; a request standing for
@@ -38,8 +41,9 @@ weighted fair admission, so verdicts covering many rows stop queueing
 behind long tails of singletons.
 
 The scheduler is the state machine ``docs/serving.md`` documents:
-QUEUED → LIVE (admitted, prefilled into a slot) → DONE (answer token
-or budget), with the slot returning to the free list mid-decode.
+QUEUED → prefill emits the first token → DONE (answer token or a
+budget of one), or LIVE → decode → DONE, with the slot returning to
+the free list mid-decode.
 """
 from __future__ import annotations
 
@@ -63,7 +67,7 @@ class Request:
     rid: int
     prompt: str
     tokens: np.ndarray  # (max_seq,) int32, SEP-terminated
-    length: int  # real token count (pos starts at length - 1)
+    length: int  # real token count (decode starts at position length)
     weight: float = 1.0
     seq: int = 0  # arrival order (fairness tie-break)
     t_submit: float = 0.0
@@ -116,10 +120,15 @@ class SlotScheduler:
         self._pos = jnp.zeros(b, dtype=jnp.int32)
         self._live = jnp.zeros(b, dtype=bool)
         self._rem = jnp.zeros(b, dtype=jnp.int32)
+        # per-slot (first token ‖ finished at it) of the admissions
+        self._head = jnp.zeros(2 * b, dtype=jnp.int32)
+        # slots admitted since the last fetch: their prefill's token
+        # has not reached the host yet
+        self._fresh: list[int] = []
 
     # ------------------------------------------------------------- state
     def live_slots(self) -> list[int]:
-        """Indices of slots currently decoding a request."""
+        """Indices of slots holding an unfinished request."""
         return [s for s, r in enumerate(self._slot_req) if r is not None]
 
     def free_slots(self) -> list[int]:
@@ -184,15 +193,16 @@ class SlotScheduler:
                     adm[j, -1] = req.length
                     real_tokens += req.length
                     self._slot_req[slot] = req
+                    self._fresh.append(slot)
                     req.t_admit = now
                     wait = now - req.t_submit
                     eng.stats.queue_wait_s += wait
                     eng.stats.queue_wait_max_s = max(
                         eng.stats.queue_wait_max_s, wait)
-                (self._cache, self._cur, self._pos, self._live,
-                 self._rem) = eng._prefill_insert(
+                (self._cache, self._cur, self._pos, self._live, self._rem,
+                 self._head) = eng._prefill_insert(
                     eng.params, self._cache, self._cur, self._pos,
-                    self._live, self._rem, jnp.asarray(adm))
+                    self._live, self._rem, self._head, jnp.asarray(adm))
                 eng.stats.batches += 1
                 eng.stats.prefill_tokens += real_tokens
                 # every token position the launch computes, padding included
@@ -202,37 +212,60 @@ class SlotScheduler:
 
     # ------------------------------------------------------------- round
     def _round(self) -> None:
-        """One decode step over the live slot mix + the round's single
-        packed device→host fetch; finished slots free mid-decode."""
+        """The round's single packed device→host fetch: the first tokens
+        of the admissions since the last fetch and, if a slot is known
+        to be live past its first token, one decode step over the live
+        slot mix; finished slots free mid-decode."""
         eng = self.engine
-        live = self.live_slots()
-        if not live:
+        fresh = self._fresh
+        known = [s for s in self.live_slots() if s not in fresh]
+        if not fresh and not known:
             return
         with spans.span("serving.round"):
             b = eng.batch_size
-            (self._cache, self._cur, self._pos, self._live, self._rem,
-             packed) = eng._decode_round(eng.params, self._cache, self._cur,
-                                         self._pos, self._live, self._rem)
+            packed = self._head
+            if known:
+                (self._cache, self._cur, self._pos, self._live, self._rem,
+                 packed) = eng._decode_round(
+                    eng.params, self._cache, self._cur, self._pos,
+                    self._live, self._rem, self._head)
             with spans.span("serving.fetch"):
                 out = np.asarray(packed)  # THE one host sync of this round
             HOST_SYNCS.tick(site="serving_round")
-            emit, fin = out[:b], out[b:] != 0
+            eng.stats.rounds += 1
+            now = time.perf_counter()
+            first, fin0 = out[:b], out[b:2 * b] != 0
+            for s in fresh:
+                self._emit(s, int(first[s]), fin0[s], now)
+                eng.stats.prefill_answers += int(fin0[s])
+            self._fresh = []
+            if not known:
+                return
+            # slots the decode step ran: known live, and fresh ones that
+            # prefill's token did not finish
+            decoded = known + [s for s in fresh if not fin0[s]]
+            emit, fin = out[2 * b:3 * b], out[3 * b:] != 0
             eng.stats.decode_steps += 1
             eng.stats.slot_steps += b
-            eng.stats.live_slot_steps += len(live)
-            eng.stats.decode_tokens += len(live)
-            now = time.perf_counter()
-            for s in live:
-                req = self._slot_req[s]
-                req.out_ids.append(int(emit[s]))
-                if fin[s]:
-                    req.t_done = now
-                    eng.stats.ttv_s.append(now - req.t_submit)
-                    self._slot_req[s] = None  # slot freed mid-decode
+            eng.stats.live_slot_steps += len(decoded)
+            for s in decoded:
+                self._emit(s, int(emit[s]), fin[s], now)
+
+    def _emit(self, s: int, token: int, fin: bool, now: float) -> None:
+        """Append slot ``s``'s token to its request; a finished request
+        frees the slot mid-decode."""
+        req = self._slot_req[s]
+        req.out_ids.append(token)
+        self.engine.stats.decode_tokens += 1
+        if fin:
+            req.t_done = now
+            self.engine.stats.ttv_s.append(now - req.t_submit)
+            self._slot_req[s] = None  # slot freed mid-decode
 
     # -------------------------------------------------------------- loop
     def poll(self) -> int:
-        """One scheduling round: admit → decode the live mix → harvest
+        """One scheduling round: admit → fetch (decoding the live mix
+        first if any slot is live past its first token) → harvest
         finished → admit into the freed slots. Returns the number of
         outstanding requests (0 = drained)."""
         self._admit()

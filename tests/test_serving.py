@@ -13,6 +13,7 @@ import sys
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -24,7 +25,7 @@ from repro.core import optimize  # noqa: E402
 from repro.data import SCHEMAS  # noqa: E402
 from repro.engine import FrontDoor, result_f1  # noqa: E402
 from repro.kernels.sync import HOST_SYNCS, SERVING_SITES  # noqa: E402
-from repro.models import init_params  # noqa: E402
+from repro.models import forward, init_params, prefill  # noqa: E402
 from repro.semantic import ModelBackend, SemanticRunner  # noqa: E402
 from repro.serving.engine import ServingEngine, ServingStats  # noqa: E402
 from repro.sharding import ShardingPolicy  # noqa: E402
@@ -83,7 +84,8 @@ class TestServingEngine:
         """A profiler trace names each program by its HLO module; the
         benchmark's kernels readers look for these two names."""
         s = engine.scheduler
-        state = (engine.params, s._cache, s._cur, s._pos, s._live, s._rem)
+        state = (engine.params, s._cache, s._cur, s._pos, s._live, s._rem,
+                 s._head)
         adm = np.zeros((2, engine.max_seq + 2), dtype=np.int32)
         for fn, args, name in (
                 (engine._prefill_insert, state + (adm,),
@@ -113,10 +115,12 @@ class TestSlotScheduler:
     def test_slot_freed_mid_decode_is_reused(self):
         """A finished sequence frees its slot while neighbours are
         still decoding, and the next submit recycles it immediately."""
-        eng = _make_engine()
+        eng = _make_engine(max_new=3)
         sched = eng.scheduler
         ta = eng.submit(["first long-running prompt"])
         assert sched.live_slots() == [0]
+        eng.poll()  # prefill's token reaches the host: no decode step
+        assert eng.stats.decode_steps == 0
         eng.poll()  # request a now one round from its token budget
         tb = eng.submit([f"second wave prompt {i}" for i in range(3)])
         assert sched.live_slots() == [0, 1, 2, 3]
@@ -227,14 +231,16 @@ class TestServingStats:
         assert set(SERVING_SITES) == {"serving_round", "serving_decode"}
 
     def test_one_sync_per_round(self):
-        """The continuous path's host fetches equal its decode rounds:
-        done-masking happens on device, one packed fetch per round."""
+        """The continuous path's host fetches equal its scheduling
+        rounds: done-masking happens on device, one packed fetch per
+        round, with or without a decode step in it."""
         eng = _make_engine()
         eng.stats = ServingStats()
         before = HOST_SYNCS.site_total(SERVING_SITES)
         eng.answer([f"round sync probe {i}" for i in range(9)])
         delta = HOST_SYNCS.site_total(SERVING_SITES) - before
-        assert delta == eng.stats.decode_steps
+        assert delta == eng.stats.rounds
+        assert 0 < eng.stats.decode_steps <= eng.stats.rounds
 
     def test_queue_latency_and_ttv(self):
         eng = _make_engine()
@@ -290,6 +296,97 @@ def test_corpus_front_door_drained_vs_continuous():
             assert getattr(sd, f) == getattr(sc, f), (qid_d, f)
         # the continuous path still reports its serving-tier fetches
         assert sc.serving_syncs >= 0
+
+
+# ---------------------------------------------------------------------------
+# The first token comes from prefill
+# ---------------------------------------------------------------------------
+
+_MIXED = ["short", "a somewhat longer prompt with several more words",
+          "a prompt of middling length", "the longest prompt of them all "
+          "runs on for quite a few words before it ends"]
+
+
+def _answering_params(eng, prompts):
+    """The engine's params with the head's YES and NO columns leaning on
+    the mean final-norm state at SEP of ``prompts``, so one of the two
+    comes first at every one of them (as the benchmark's verdict head
+    does), and split along their spread so both answers occur."""
+    toks = np.stack([eng.encode_row(p)[0] for p in prompts])
+    lens = np.asarray([eng.encode_row(p)[1] for p in prompts])
+    _, h, _ = forward(_CFG, eng.policy, eng.params,
+                      {"tokens": jnp.asarray(toks)})
+    x = np.asarray(h, np.float64)[np.arange(len(prompts)), lens - 1]
+    top = x.mean(0) / np.linalg.norm(x.mean(0))
+    v = x[0] - x.mean(0)
+    v -= v.dot(top) * top
+    v /= np.abs((x - x.mean(0)).dot(v)).max()
+    head = np.asarray(eng.params["lm_head"], np.float64)
+    rest = np.abs(x.dot(head)).max()
+    assert x.dot(top).min() > 0
+    lean = (rest + 10.0) / x.dot(top).min()
+    cols = np.stack([lean * top + v, lean * top - v], 1)
+    head[:, [eng.tok.YES, eng.tok.NO]] = cols
+    return dict(eng.params, lm_head=jnp.asarray(head, jnp.float32))
+
+
+class TestPrefillFirstToken:
+    def test_prefill_last_logits_match_forward_and_drained(self, engine):
+        """``prefill(..., last=len - 1)`` gives the logits of each row's
+        last real token whatever padding follows it: those of a full
+        forward there, and those of the drained path's first step."""
+        toks, lens = engine._encode_batch(_MIXED)
+        assert len(set(lens.tolist())) == len(_MIXED)
+        batch = {"tokens": jnp.asarray(toks)}
+        got, _ = prefill(_CFG, engine.policy, engine.params, batch,
+                         max_seq=engine.cache_len,
+                         last=jnp.asarray(lens - 1))
+        full, _, _ = forward(_CFG, engine.policy, engine.params, batch)
+        rows = np.arange(len(_MIXED))
+        np.testing.assert_allclose(got, full[rows, lens - 1],
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(got, engine.first_step_logits(_MIXED),
+                                   rtol=1e-5, atol=1e-5)
+        # without ``last`` prefill keeps the last padded position
+        pad, _ = prefill(_CFG, engine.policy, engine.params, batch,
+                         max_seq=engine.cache_len)
+        np.testing.assert_allclose(pad, full[:, -1], rtol=1e-5, atol=1e-5)
+
+    def test_answering_head_costs_no_decode_step(self):
+        """A head that answers at every SEP: each request finishes at the
+        token prefill emits, no decode step runs, and the tokens are the
+        drained path's."""
+        eng = _make_engine()
+        prompts = [f"is item {i} of the catalogue in stock?"
+                   for i in range(11)] + _MIXED
+        eng.params = _answering_params(eng, prompts)
+        eng.stats = ServingStats()
+        out = eng.answer(prompts)
+        assert eng.stats.decode_steps == 0
+        assert eng.stats.prefill_answers == eng.stats.prompts == 15
+        assert eng.stats.rounds > 0
+        assert eng.stats.slot_steps == 0
+        assert eng.stats.decode_tokens == 15
+        assert set(out) == {"YES", "NO"}
+        assert out == eng.answer_drained(prompts)
+        snap = eng.stats.snapshot()
+        assert (snap["rounds"], snap["prefill_answers"]) == (
+            eng.stats.rounds, 15)
+
+    @pytest.mark.parametrize("max_new", [2, 3])
+    def test_multi_token_answers_match_drained(self, max_new):
+        """With random weights a request runs past its first token: the
+        later tokens are decoded, and all equal the drained path's."""
+        eng = _make_engine(max_new=max_new)
+        prompts = [f"multi token prompt {i}" for i in range(6)] + _MIXED
+        eng.stats = ServingStats()
+        ticket = eng.submit(prompts)
+        eng.drain(ticket)
+        ids = eng.scheduler.take(ticket)
+        assert eng.stats.decode_steps > 0
+        assert eng.stats.prefill_answers < len(prompts)
+        assert max(len(t) for t in ids) == max_new
+        assert [eng._detok(t) for t in ids] == eng.answer_drained(prompts)
 
 
 class TestHashTokenizer:

@@ -181,8 +181,10 @@ def test_one_query_spans_every_layer(recording):
         elif s.name == "serving.fetch":
             assert parent(s) == "serving.round"
         assert query.start_ns <= s.start_ns <= s.end_ns <= query.end_ns
-    # one fetch a decode round, one admission span a prefill launch
-    assert names["serving.fetch"] == eng.stats.decode_steps
+    # one fetch a scheduling round (with or without a decode step in
+    # it), one admission span a prefill launch
+    assert names["serving.fetch"] == names["serving.round"] \
+        == eng.stats.rounds >= eng.stats.decode_steps
     assert names["serving.admit"] == eng.stats.batches
     # every launch computes max_seq positions a row, padding included
     assert eng.stats.prefill_token_slots == (eng.stats.prefill_rows
