@@ -5,7 +5,9 @@ Everything that belongs to a configuration, a traffic mix or a metric is
 a file of its own, found by the name ``BENCHMARK.json`` gives it:
 
 * ``configs/<config>.json`` — the deployment: schema, scale, backend and,
-  for an LM backend, the model's sizes and the logit-gap limit;
+  for an LM backend, the model's sizes, its family module (``"family"``,
+  a path from the checkout's root; ``reference/lm.py`` says what such a
+  module gives) and the logit-gap limit;
 * ``traffic/<traffic>.json`` — the templates of a cycle, the cache scope
   (``per_query`` or ``shared``) and the warm-up cycles;
 * ``queries/<schema>.json`` — the templates, as data;
@@ -24,6 +26,7 @@ import tempfile
 import time
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
 from typing import Optional
 
 import jax
@@ -66,6 +69,7 @@ class Run:
     serving: Optional[dict] = None
     served: Optional[list] = None
     model: Optional[dict] = None
+    family: Optional[ModuleType] = None
 
     @property
     def window_s(self) -> float:
@@ -112,14 +116,31 @@ def cell_metrics(bench: dict, name: str, trace: bool) -> list[dict]:
     return [m for m in group if name in m.get("workloads", [name])]
 
 
-def read_metric(metric: dict, run: Run):
-    """Value of ``metric`` from its reader, or None if it found nothing."""
-    path = HERE / "metrics" / f"{metric['name']}.py"
-    spec = importlib.util.spec_from_file_location(
-        "perfbench_metric_" + metric["name"].replace(".", "_"), path)
+def _load(path: Path, name: str) -> ModuleType:
+    """The Python file at ``path``, executed as a module called ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read(run)
+    return mod
+
+
+def read_metric(metric: dict, run: Run):
+    """Value of ``metric`` from its reader, or None if it found nothing."""
+    return _load(HERE / "metrics" / f"{metric['name']}.py",
+                 "perfbench_metric_" + metric["name"].replace(".", "_")
+                 ).read(run)
+
+
+def load_family(config: dict, root: Path = ROOT) -> ModuleType:
+    """The model family module an LM configuration names under
+    ``"family"``, a path from the checkout's ``root``."""
+    path = config.get("family")
+    if not path:
+        raise ValueError(
+            f"configuration {config.get('name')!r} has an LM backend and no "
+            f"\"family\" key: name its model family module, a path from the "
+            f"checkout's root such as \"perfbench/reference/dense.py\"")
+    return _load(root / path, "perfbench_family_" + re.sub(r"\W", "_", path))
 
 
 def predicates(templates: dict) -> set[str]:
@@ -238,7 +259,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     all_templates = load_templates(config["schema"])
     templates = {n: all_templates[n] for n in traffic["templates"]}
     longest = longest_prompt_tokens(data, templates)
-    system = systems.build(config, data, seed, spans, longest_prompt=longest,
+    family = load_family(config, root) if config["backend"] == "lm" else None
+    system = systems.build(config, data, seed, spans, family=family,
+                           longest_prompt=longest,
                            groups=sample_prompts(data, templates,
                                                  HEAD_SAMPLE))
     ref = Relational(data, importlib.import_module(
@@ -352,7 +375,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
               if dev.platform == "tpu" else {}, templates=templates,
               table_rows={t: data.num_rows(t) for t in data.tables},
               trace=summary,
-              serving=serving, served=served, model=system.model)
+              serving=serving, served=served, model=system.model,
+              family=family)
     metrics = {}
     for m in cell_metrics(bench, workload, trace):
         v = read_metric(m, run)
@@ -364,8 +388,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         sample = _sample_served(served, seed, lm_sample)
         del system, eng, run
         gc.collect()
-        weights = lm.with_head(lm.init_weights(model, seed), head)
-        gaps = lm.served_gaps(model, weights, sample, length=longest + 1)
+        weights = lm.with_head(family.init_weights(model, seed), head)
+        gaps = lm.served_gaps(family, model, weights, sample,
+                              length=longest + 1)
         log.line(f"[check] lm tokens={len(gaps)} requests={len(sample)} "
                  f"served_below_best={int((gaps > 0).sum())} served_gap_max"
                  f"{_by_sample(gaps, sample, np.max)} served_gap_mean"
@@ -375,8 +400,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             checks["lm_logit_gap_mean_served"] = {
                 "value": float(gaps.mean()), "limit": limit}
             for mode in control[::-1]:
-                gaps = lm.served_gaps(model, weights, sample, quant=mode,
-                                      length=longest + 1)
+                gaps = lm.served_gaps(family, model, weights, sample,
+                                      quant=mode, length=longest + 1)
                 log.line(f"[check] lm {mode} below_best="
                          f"{int((gaps > 0).sum())} gap_max"
                          f"{_by_sample(gaps, sample, np.max)} gap_mean"
@@ -471,9 +496,7 @@ def _serving_counts(eng) -> dict:
         return {}
     s = eng.stats
     return {"prefill_tokens": s.prefill_tokens,
-            "decode_tokens": s.decode_tokens, "slot_steps": s.slot_steps,
-            "live_slot_steps": s.live_slot_steps,
-            "decode_steps": s.decode_steps}
+            "prefill_token_slots": s.prefill_token_slots}
 
 
 class _Log:

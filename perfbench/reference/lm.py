@@ -1,20 +1,30 @@
-"""The served LM's weights from the seed, and its plain reference.
+"""What every served LM shares, whatever its family: the tokens, the
+weights drawn from the seed, the linear layer of the plain reference,
+the logit check and the verdict head.
 
-``init_weights`` draws the weights on the device in one jitted call, in
-the type they are served in (bf16), laid out as the program's dense
-decoder expects them, and ``verdict_head`` chooses the head's YES and NO
-columns so that the random model answers every prompt with one of them,
-YES to about half; the harness hands the weights to the program, and the
-reference draws the same weights again from the same seed, with the
-same two columns, once the program's state is freed.
+A configuration names its model family under ``"family"``: a module,
+given as a path from the checkout's root, which the harness loads by
+path (``harness.load_family``). It gives
 
-``forward_logits`` is the plain forward pass: float32 at ``highest``
-matmul precision, one layer at a time, with no cache and no batching
-across prompts beyond padding at the end (causal, so padding never
-reaches an earlier position). It computes what the program's dense
-model computes — RMSNorm, rotary embedding over the whole head, SwiGLU
-— which departs from the published stablelm-3b (LayerNorm, 25% partial
-rotary); the configuration file lists the departures.
+* ``program_config(model, name)``: the program's ``ModelConfig`` for the
+  configuration's ``model`` group (the published ``config.json`` keys);
+* ``init_weights(model, seed, dtype)``: the program's parameter tree for
+  the family, drawn on the device from the seed (``draw_weights``), with
+  the output head at ``lm_head`` (d_model, vocab);
+* ``forward_hidden(model, weights, tokens, positions, quant=None)``: the
+  plain float32 forward at ``HIGHEST`` precision, one layer at a time,
+  every linear layer through ``_dense`` so that the controls' int8 and
+  fp8 reach every family;
+* ``matmul_params(model)`` and ``request_flops(model, n_prompt, n_out)``:
+  the parameters a token passes through in a matmul, and a served
+  request's model FLOPs.
+
+``perfbench/reference/dense.py`` is the dense decoder's. The harness
+hands the weights to the program, and the reference draws the same
+weights again from the same seed, with the same head, once the
+program's state is freed. ``verdict_head`` chooses the head's YES and
+NO columns so that the random model answers every prompt with one of
+them, YES to about half.
 
 ``quant="int8"`` is the control: every linear layer computed in int8
 (weights per output channel, activations per token, int32 sums), the
@@ -22,7 +32,6 @@ step below bf16 that would tempt a later change.
 """
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import jax
@@ -71,39 +80,18 @@ def answer_ids(answer: str) -> list[int]:
 HEAD_SCALE = 0.01
 
 
-def _shapes(m: dict) -> dict:
-    """Each leaf's (shape, std); a std of None is a norm gain of ones."""
-    L, D, H = m["num_hidden_layers"], m["hidden_size"], \
-        m["num_attention_heads"]
-    K, F, V = m["num_key_value_heads"], m["intermediate_size"], \
-        m["vocab_size"]
-    hd = D // H
-    d, f, o = 1 / math.sqrt(D), 1 / math.sqrt(F), 1 / math.sqrt(H * hd)
-    return {"embed": ((V, D), d), "final_ln": ((D,), None),
-            "lm_head": ((D, V), HEAD_SCALE * d),
-            "blocks": {"ln1": ((L, D), None), "ln2": ((L, D), None),
-                       "attn": {"wq": ((L, D, H, hd), d),
-                                "wk": ((L, D, K, hd), d),
-                                "wv": ((L, D, K, hd), d),
-                                "wo": ((L, H, hd, D), o)},
-                       "mlp": {"w_in": ((L, D, F), d),
-                               "w_out": ((L, F, D), f),
-                               "w_gate": ((L, D, F), d)}}}
-
-
 def _key(seed: int):
     seed = int(seed) % 2**64
     return jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF),
                               seed >> 32)
 
 
-def init_weights(model: dict, seed: int, dtype=jnp.bfloat16) -> dict:
-    """Weights of ``model`` (the configuration's ``model`` group) from
-    ``seed``: normal with std 1/sqrt(fan-in), the output head at
-    ``HEAD_SCALE`` times that, norm gains 1. One jitted
-    call; stacked layer leaves are drawn a layer at a time so no float32
-    copy of a whole leaf is ever live."""
-    shapes = _shapes(model)
+def draw_weights(shapes: dict, seed: int, dtype=jnp.bfloat16) -> dict:
+    """A tree of weights from ``seed``, one leaf for each (shape, std)
+    of ``shapes``: normal with that std, or ones where the std is None
+    (a norm gain). One jitted call; a leaf of more than two axes is
+    drawn one slice of its first (stacked layer) axis at a time, so no
+    float32 copy of a whole leaf is ever live."""
     flat, tree = jax.tree.flatten(shapes, is_leaf=lambda x: isinstance(
         x, tuple) and isinstance(x[0], tuple))
 
@@ -161,84 +149,19 @@ def _dense(x, w, quant):
     return y.reshape(*x.shape[:-1], *out_shape)
 
 
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
-        * w.astype(jnp.float32)
-
-
-def _rope(x, theta):
-    S, hd = x.shape[-3], x.shape[-1]
-    half = hd // 2
-    freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32)
-                    / half)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * freqs
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-@partial(jax.jit, static_argnames=("eps", "theta", "quant"))
-def _layer(h, lw, *, eps, theta, quant):
-    B, S, D = h.shape
-    a = lw["attn"]
-    x = _rms(h, lw["ln1"], eps)
-    q = _rope(_dense(x, a["wq"], quant), theta)
-    k = _rope(_dense(x, a["wk"], quant), theta)
-    v = _dense(x, a["wv"], quant)
-    H, hd = q.shape[-2], q.shape[-1]
-    G = H // k.shape[-2]
-    k = jnp.repeat(k, G, axis=2)
-    v = jnp.repeat(v, G, axis=2)
-    s = jnp.einsum("bshd,bthd->bhst", q, k, precision=HIGHEST)
-    s = s / math.sqrt(hd)
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    s = jnp.where(causal, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhst,bthd->bshd", p, v, precision=HIGHEST)
-    h = h + _dense(o.reshape(B, S, H * hd),
-                   a["wo"].reshape(H * hd, D), quant)
-    m = lw["mlp"]
-    x = _rms(h, lw["ln2"], eps)
-    g = _dense(x, m["w_gate"], quant)
-    u = _dense(x, m["w_in"], quant)
-    return h + _dense(jax.nn.silu(g) * u, m["w_out"], quant)
-
-
-@partial(jax.jit, static_argnames=("eps",))
-def _final(h, final_ln, rows, cols, *, eps):
-    return _rms(h[rows, cols], final_ln, eps)
-
-
-def forward_hidden(model: dict, weights: dict, tokens: np.ndarray,
-                   positions: tuple[np.ndarray, np.ndarray],
-                   quant=None) -> jnp.ndarray:
-    """Float32 final-norm hidden states (len(rows), d_model) at
-    ``positions`` = (rows, cols) of the (B, S) ``tokens``, layer by
-    layer."""
-    eps = float(model["layer_norm_eps"])
-    theta = float(model["rope_theta"])
-    h = jnp.take(weights["embed"], jnp.asarray(tokens), axis=0).astype(
-        jnp.float32)
-    blocks = weights["blocks"]
-    for i in range(model["num_hidden_layers"]):
-        lw = jax.tree.map(lambda x: x[i], blocks)
-        h = _layer(h, lw, eps=eps, theta=theta, quant=quant)
-    rows, cols = positions
-    return _final(h, weights["final_ln"], jnp.asarray(rows),
-                  jnp.asarray(cols), eps=eps)
-
-
 @partial(jax.jit, static_argnames=("quant",))
 def _head(x, lm_head, *, quant):
     return _dense(x, lm_head, quant)
 
 
-def forward_logits(model: dict, weights: dict, tokens: np.ndarray,
+def forward_logits(family, model: dict, weights: dict, tokens: np.ndarray,
                    positions: tuple[np.ndarray, np.ndarray],
                    quant=None) -> jnp.ndarray:
     """Float32 logits (len(rows), vocab) at ``positions`` = (rows, cols)
-    of the (B, S) ``tokens``, layer by layer."""
-    x = forward_hidden(model, weights, tokens, positions, quant=quant)
+    of the (B, S) ``tokens``: ``family``'s final-norm hidden states
+    through the output head ``lm_head``."""
+    x = family.forward_hidden(model, weights, tokens, positions,
+                              quant=quant)
     return _head(x, weights["lm_head"], quant=quant)
 
 
@@ -250,13 +173,14 @@ def _pad(seqs: list, length: int) -> np.ndarray:
     return tokens
 
 
-def served_gaps(model: dict, weights: dict, served: list, quant=None,
-                block: int = 64, length: int = 0) -> np.ndarray:
+def served_gaps(family, model: dict, weights: dict, served: list,
+                quant=None, block: int = 64, length: int = 0) -> np.ndarray:
     """For each served token of ``served`` ([(prompt, [token ids])]): how
-    far its reference logit lies below the reference's best at that
-    position. With ``quant``, the gap of the token the quantized forward
-    puts first instead (the control). Blocks are padded to ``length``
-    tokens at least, so every block has one shape."""
+    far its reference logit, through ``family``'s forward, lies below the
+    reference's best at that position. With ``quant``, the gap of the
+    token the quantized forward puts first instead (the control). Blocks
+    are padded to ``length`` tokens at least, so every block has one
+    shape."""
     vocab = model["vocab_size"]
     gaps = []
     for s in range(0, len(served), block):
@@ -272,10 +196,10 @@ def served_gaps(model: dict, weights: dict, served: list, quant=None,
         seqs += [[PAD]] * (block - len(seqs))
         tokens = _pad(seqs, length)
         pos = (np.asarray(rows), np.asarray(cols))
-        ref = forward_logits(model, weights, tokens, pos)
+        ref = forward_logits(family, model, weights, tokens, pos)
         if quant is not None:
-            picks = np.asarray(jnp.argmax(
-                forward_logits(model, weights, tokens, pos, quant=quant), -1))
+            picks = np.asarray(jnp.argmax(forward_logits(
+                family, model, weights, tokens, pos, quant=quant), -1))
         ref = np.asarray(ref)
         picks = np.asarray(picks)
         gaps.append(ref.max(-1) - ref[np.arange(len(picks)), picks])
@@ -291,27 +215,28 @@ VERDICT_MARGIN = 1.0
 VERDICT_SPREAD = 4.0
 
 
-def verdict_head(model: dict, weights: dict, groups: list, seed: int,
-                 length: int) -> np.ndarray:
+def verdict_head(family, model: dict, weights: dict, groups: list,
+                 seed: int, length: int) -> np.ndarray:
     """The output head's YES and NO columns (d_model, 2), float32.
 
     Random columns would almost never put YES or NO first, so every
-    verdict would be NO and every result nearly empty. From the final-norm
-    hidden states at SEP of ``groups`` (one list of prompts per semantic
-    predicate), computed with bf16 operands: both columns lean on the
-    states' mean direction, so one of the two comes first at every SEP,
-    and they differ along a direction drawn from ``seed`` with each
-    group's mean state projected out, so each predicate says YES to
-    about half of its prompts."""
-    vocab, D = model["vocab_size"], model["hidden_size"]
+    verdict would be NO and every result nearly empty. From ``family``'s
+    final-norm hidden states at SEP of ``groups`` (one list of prompts
+    per semantic predicate), computed with bf16 operands: both columns
+    lean on the states' mean direction, so one of the two comes first
+    at every SEP, and they differ along a direction drawn from ``seed``
+    with each group's mean state projected out, so each predicate says
+    YES to about half of its prompts."""
+    vocab = model["vocab_size"]
     xs = []
     for g in groups:
         seqs = [prompt_ids(p, vocab) for p in g]
-        x = forward_hidden(model, weights, _pad(seqs, length),
-                           (np.arange(len(seqs)),
-                            np.asarray([len(q) - 1 for q in seqs])),
-                           quant="bf16")
+        x = family.forward_hidden(model, weights, _pad(seqs, length),
+                                  (np.arange(len(seqs)),
+                                   np.asarray([len(q) - 1 for q in seqs])),
+                                  quant="bf16")
         xs.append(np.asarray(x, np.float64))
+    D = xs[0].shape[1]
     mean = np.concatenate(xs).mean(0)
     top = mean / np.linalg.norm(mean)
     lean = VERDICT_MARGIN / np.concatenate(xs).dot(top).min()

@@ -11,13 +11,13 @@ served for each prompt, from which the checks read the verdicts.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from types import ModuleType
 from typing import Optional, Sequence
 
 import jax.numpy as jnp
 import numpy as np
 
 from repro.engine import Database, FrontDoor
-from repro.models.config import ModelConfig
 from repro.semantic import ModelBackend, OracleBackend, SemanticRunner
 from repro.serving.engine import ServingEngine
 from repro.sharding.policy import ShardingPolicy
@@ -94,18 +94,6 @@ class SpanBackend:
             return self.inner.collect(handles)
 
 
-def model_config(model: dict, name: str) -> ModelConfig:
-    """The program's ``ModelConfig`` for a configuration's model group."""
-    return ModelConfig(
-        name=name, family="dense",
-        num_layers=model["num_hidden_layers"], d_model=model["hidden_size"],
-        num_heads=model["num_attention_heads"],
-        num_kv_heads=model["num_key_value_heads"],
-        d_ff=model["intermediate_size"], vocab_size=model["vocab_size"],
-        gated_mlp=True, norm_eps=float(model["layer_norm_eps"]),
-        rope_theta=float(model["rope_theta"]))
-
-
 @dataclass
 class System:
     """Everything the window drives, and what the checks read back."""
@@ -117,12 +105,16 @@ class System:
     engine: Optional[RecordingEngine] = None
     model: Optional[dict] = None
     head: Optional[np.ndarray] = None
+    family: Optional[ModuleType] = None
 
 
-def build(config: dict, data, seed: int, spans, longest_prompt: int = 0,
+def build(config: dict, data, seed: int, spans,
+          family: Optional[ModuleType] = None, longest_prompt: int = 0,
           groups: Sequence[list] = ()) -> System:
     """Load ``data`` and wire the configured backend behind a front door.
-    For an LM backend, ``longest_prompt`` (tokens) sizes ``max_seq`` and
+    For an LM backend, ``family`` (the module the configuration names,
+    ``reference/lm.py``) maps the model group to the program's config and
+    draws the weights, ``longest_prompt`` (tokens) sizes ``max_seq`` and
     ``groups`` (sample prompts, one list per predicate) choose the
     verdict head (``reference.lm.verdict_head``)."""
     db = Database()
@@ -134,12 +126,13 @@ def build(config: dict, data, seed: int, spans, longest_prompt: int = 0,
         inner = OracleBackend(truths=db.truths)
     elif kind == "lm":
         model = config["model"]
-        weights = lm.init_weights(model, seed, dtype=jnp.bfloat16)
-        head = lm.verdict_head(model, weights, groups, seed,
+        weights = family.init_weights(model, seed, dtype=jnp.bfloat16)
+        head = lm.verdict_head(family, model, weights, groups, seed,
                                length=longest_prompt + 1)
         engine = RecordingEngine(ServingEngine(
-            model_config(model, config["name"]), lm.with_head(weights, head),
-            ShardingPolicy.single(), max_seq=longest_prompt))
+            family.program_config(model, config["name"]),
+            lm.with_head(weights, head), ShardingPolicy.single(),
+            max_seq=longest_prompt))
         del weights
         inner = ModelBackend.from_engine(engine)
     else:
@@ -147,4 +140,5 @@ def build(config: dict, data, seed: int, spans, longest_prompt: int = 0,
     backend = SpanBackend(inner, spans)
     front = FrontDoor(db, SemanticRunner(backend))
     return System(db=db, catalog=catalog, front=front, backend=backend,
-                  engine=engine, model=config.get("model"), head=head)
+                  engine=engine, model=config.get("model"), head=head,
+                  family=family)

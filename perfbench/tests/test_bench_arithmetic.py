@@ -3,7 +3,8 @@ import itertools
 
 import pytest
 
-from perfbench import flops, window
+from perfbench import window
+from perfbench.reference import dense
 
 
 def test_cycles_are_seeded_permutations():
@@ -62,9 +63,9 @@ def test_stablelm_matmul_params_match_the_program_count():
     # layer; only the embedding gather and the gains take no matmul
     embed = cfg.vocab_size * cfg.d_model
     gains = 2 * cfg.d_model * cfg.num_layers
-    assert flops.lm_matmul_params(STABLELM) == \
+    assert dense.matmul_params(STABLELM) == \
         cfg.param_count() - embed - gains
-    assert flops.lm_matmul_params(STABLELM) == 2_666_332_160
+    assert dense.matmul_params(STABLELM) == 2_666_332_160
 
 
 def test_request_flops_by_hand():
@@ -73,11 +74,15 @@ def test_request_flops_by_hand():
          "intermediate_size": 8, "vocab_size": 10}
     per_layer = 4 * 4 * 4 + 3 * 4 * 8
     params = 2 * per_layer + 4 * 10
-    assert flops.lm_matmul_params(m) == params
+    assert dense.matmul_params(m) == params
     attn = 4 * 2 * 4
-    # 3 prompt tokens attend to 1, 2, 3 keys; 2 decode steps to 3, 4
-    want = (3 * 2 * params + attn * 6) + (2 * 2 * params + attn * 7)
-    assert flops.lm_request_flops(m, 3, 2) == want
+    # 3 prompt tokens attend to 1, 2, 3 keys, and prefill gives the first
+    # of 2 answer tokens; one decode pass, at position 3, to 4 keys
+    want = (3 * 2 * params + attn * 6) + (2 * params + attn * 4)
+    assert dense.request_flops(m, 3, 2) == want
+    # an answer of one token is all prefill
+    assert dense.request_flops(m, 3, 1) == dense.request_flops(m, 3, 0) \
+        == 3 * 2 * params + attn * 6
 
 
 def test_peaks_refuse_an_unknown_device():

@@ -6,8 +6,12 @@ import shutil
 import subprocess
 import sys
 
+import pytest
+
 from conftest import SEED, lm_config
-from perfbench.harness import ROOT, cell_metrics, run_cell
+from perfbench import harness
+from perfbench.harness import ROOT, Run, cell_metrics, read_metric, run_cell
+from perfbench.tracing import Spans
 
 
 def _cpu_env(**extra):
@@ -60,3 +64,42 @@ def test_lm_cell_end_to_end(bench):
             assert {"queries_per_s", "query_p95_s", "setup_s"} <= set(
                 r["metrics"])
     json.dumps(r)
+
+
+def test_a_family_added_as_a_file_is_the_one_used(monkeypatch, bench):
+    # a configuration names a family module that no file of the harness
+    # knows of; the run is correct and went through that module
+    loaded, real = [], harness.load_family
+
+    def spy(config, root=ROOT):
+        loaded.append(real(config, root))
+        return loaded[-1]
+    monkeypatch.setattr(harness, "load_family", spy)
+    cfg = lm_config()
+    cfg["family"] = "perfbench/tests/counting_family.py"
+    r = run_cell("ecom-lm", SEED, 0.5, False, require_tpu=False,
+                 bench=bench, configs={"ecommerce-stablelm-3b": cfg})
+    assert r["correct"] and r["attempted"] >= 4
+    (family,) = loaded
+    assert family.__file__.endswith("counting_family.py")
+    calls = family.CALLS
+    # the program's config, the weights for the program and again for
+    # the reference, the verdict head's and the logit check's forwards
+    assert calls["program_config"] == 1 and calls["init_weights"] == 2
+    assert calls["forward_hidden"] >= 2
+    # the readers count a request's FLOPs through the run's family
+    served = [("a b c", [2]), ("x y", [7, 3])]
+    run = Run(records=[], t0=0.0, t1=1.0, setup_s=0.0, peak_bytes=0,
+              spans=Spans(), templates={}, table_rows={},
+              peaks={"bf16_flops": 1e12}, served=served, model=cfg["model"],
+              family=family)
+    assert read_metric({"name": "serving.mfu"}, run) > 0
+    assert calls["request_flops"] == len(served)
+
+
+def test_an_lm_configuration_without_a_family_is_refused(bench):
+    cfg = lm_config()
+    del cfg["family"]
+    with pytest.raises(ValueError, match="no \"family\" key"):
+        run_cell("ecom-lm", SEED, 0.5, False, require_tpu=False,
+                 bench=bench, configs={"ecommerce-stablelm-3b": cfg})
